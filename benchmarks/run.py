@@ -63,6 +63,9 @@ def main() -> None:
         benches = {k: v for k, v in benches.items() if k in names}
 
     import jax
+
+    from repro.util import enable_compile_cache
+    enable_compile_cache()
     backend = jax.default_backend()
 
     print("name,us_per_call,stable,derived")

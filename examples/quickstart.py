@@ -28,9 +28,10 @@ def main():
     print(f"recall@10 = {r:.3f}  (paper constraint: >= 0.8)")
     assert r >= 0.8
 
-    # the same search through the Pallas kernel path (interpret on CPU)
+    # the same search through the Pallas kernel path (interpreted on the
+    # CPU, compiled by Mosaic on a TPU, where onehot is the strategy)
     params_k = SearchParams(nprobe=16, k=10, use_kernels=True,
-                            query_chunk=32)
+                            strategy="onehot", query_chunk=32)
     _, ids_k = search_ivfpq(index, clusters, ds.queries, params_k)
     rk = float(recall_at_k(ids_k, ds.groundtruth))
     print(f"recall@10 via Pallas kernels = {rk:.3f}")

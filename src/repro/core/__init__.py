@@ -20,7 +20,7 @@ from repro.core.multiplierless import (make_square_lut, square_via_lut,
                                        build_lut_int_reference,
                                        scan_codes_int, quantize_residual)
 from repro.core.dpq import train_dpq
-from repro.core.topk import topk_smallest, merge_topk, running_topk_update
+from repro.core.topk import topk_smallest, merge_topk
 from repro.core.search import (SearchParams, search_ivfpq, exact_search,
                                recall_at_k, cluster_locate)
 
@@ -39,7 +39,7 @@ __all__ = [
     "build_lut_multiplierless", "build_lut_int_reference", "scan_codes_int",
     "quantize_residual",
     "train_dpq",
-    "topk_smallest", "merge_topk", "running_topk_update",
+    "topk_smallest", "merge_topk",
     "SearchParams", "search_ivfpq", "exact_search", "recall_at_k",
     "cluster_locate",
 ]

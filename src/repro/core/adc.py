@@ -34,6 +34,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.kmeans import HIGHEST
 from repro.core.pq import PQCodebook
 
 
@@ -44,7 +45,8 @@ def build_lut(codebook: PQCodebook, residual: jax.Array) -> jax.Array:
     which is how the MXU wants it. Exact for f32 inputs (modulo fp assoc.).
     """
     r = residual.astype(jnp.float32).reshape(codebook.m, 1, codebook.dsub)
-    cross = jnp.einsum("mkd,mcd->mc", r, codebook.codebooks)    # (M, CB)
+    cross = jnp.einsum("mkd,mcd->mc", r, codebook.codebooks,
+                       precision=HIGHEST)                       # (M, CB)
     rsq = jnp.sum(r * r, axis=-1)                               # (M, 1)
     return jnp.maximum(rsq + codebook.sqnorms - 2.0 * cross, 0.0)
 
@@ -85,7 +87,8 @@ def scan_codes_onehot(lut: jax.Array, codes: jax.Array,
     cbn = lut.shape[1]
     onehot = jax.nn.one_hot(codes.astype(jnp.int32), cbn, dtype=compute_dtype)
     flat = onehot.reshape(codes.shape[0], -1)                   # (C, M*CB)
-    return flat @ lut.reshape(-1).astype(compute_dtype)
+    return jnp.matmul(flat, lut.reshape(-1).astype(compute_dtype),
+                      precision=HIGHEST)
 
 
 def adc_distances(lut: jax.Array, codes: jax.Array, sizes: jax.Array | None
@@ -159,7 +162,8 @@ def scan_codes_quantized(qlut: QuantizedLUT, codes: jax.Array) -> jax.Array:
     """
     gathered = jax.vmap(lambda l, c: l[c], in_axes=(0, 1), out_axes=1)(
         qlut.lut_q, codes.astype(jnp.int32))                  # (C, M) u8
-    acc = gathered.astype(jnp.float32) @ qlut.scale           # (C,)
+    acc = jnp.matmul(gathered.astype(jnp.float32), qlut.scale,
+                     precision=HIGHEST)                       # (C,)
     return acc + jnp.sum(qlut.bias)
 
 
@@ -182,7 +186,8 @@ def scan_codes_onehot_quantized(qlut: QuantizedLUT,
         onehot, qlut.lut_q.astype(jnp.bfloat16),
         dimension_numbers=(((2,), (1,)), ((1,), (0,))),
         preferred_element_type=jnp.float32)                   # (M, C)
-    return qlut.scale @ acc + jnp.sum(qlut.bias)
+    return (jnp.matmul(qlut.scale, acc, precision=HIGHEST)
+            + jnp.sum(qlut.bias))
 
 
 def adc_distances_quantized(qlut: QuantizedLUT, codes: jax.Array,

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kmeans import kmeans
+from repro.core.topk import smallest_k
 
 
 class Coarse2(NamedTuple):
@@ -103,9 +104,9 @@ def coarse2_locate(coarse: Coarse2, queries: jax.Array, *, nprobe: int,
           - 2.0 * jnp.einsum("qd,qsd->qs", q, cc))
     d2 = jnp.maximum(d2, 0.0)
     d2 = jnp.where(cand >= 0, d2, jnp.inf)                   # mask pads
-    nd, idx = jax.lax.top_k(-d2, nprobe)
-    probes = jnp.take_along_axis(cand, idx, axis=1)
-    return probes.astype(jnp.int32), -nd
+    d, idx = smallest_k(d2, nprobe)          # (+inf, -1) past the candidates
+    probes = jnp.take_along_axis(cand, jnp.maximum(idx, 0), axis=1)
+    return jnp.where(idx >= 0, probes, -1).astype(jnp.int32), d
 
 
 def routing_rows_touched(nlist: int, n_groups: int, gmax: int,

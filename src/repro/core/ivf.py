@@ -1,9 +1,10 @@
 """IVF-PQ index construction and the padded cluster layout.
 
 Build pipeline (matches Faiss IVFPQ / the paper's engine):
-  1. coarse k-means over the corpus -> nlist centroids
-  2. residual = point - centroid[assign]
-  3. PQ-train on residuals (or OPQ rotation first), encode all residuals
+  1. coarse k-means over the training set (a sample, or the corpus)
+     -> nlist centroids
+  2. PQ-train (or OPQ) on the training set's residuals
+  3. assign + residual + encode every point, in chunks (``assign_encode``)
   4. group codes by cluster
 
 JAX wants static shapes, so the grouped layout pads every cluster to
@@ -15,16 +16,15 @@ duplicated clusters) into per-shard arrays of exactly this shape.
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.kmeans import kmeans, assign_chunked
+from repro.core.kmeans import kmeans, assign_chunked, l2_sq
 from repro.core.pq import (PQCodebook, OPQCodebook, train_pq, train_opq,
-                           encode_pq, code_dtype)
+                           encode_pq)
 
 
 class IVFPQIndex(NamedTuple):
@@ -60,11 +60,40 @@ class PaddedClusters(NamedTuple):
         return self.codes.shape[1]
 
 
+@jax.jit
+def assign_encode(points: jax.Array, centroids: jax.Array,
+                  codebook: PQCodebook, rotation: Optional[jax.Array] = None):
+    """Coarse assignment + residual + PQ encoding, chunked over points so
+    the f32 copy, residuals and distance blocks exist for one chunk at a
+    time (what lets a 10M-point build fit in HBM).  Per-row math is that
+    of ``assign_chunked`` then ``encode_pq``.
+    -> (assign (N,) i32, codes (N, M))."""
+    n, d = points.shape
+    chunk = 16384                         # assign_chunked's default
+    pad = (-n) % chunk
+    pts = jnp.pad(points, ((0, pad), (0, 0))).reshape(-1, chunk, d)
+
+    def body(_, pc):
+        x = pc.astype(jnp.float32)
+        a = jnp.argmin(l2_sq(x, centroids), axis=1).astype(jnp.int32)
+        r = x - centroids[a]
+        if rotation is not None:
+            r = r @ rotation
+        return None, (a, encode_pq(codebook, r))
+
+    _, (assign, codes) = jax.lax.scan(body, None, pts)
+    return assign.reshape(-1)[:n], codes.reshape(-1, codes.shape[-1])[:n]
+
+
 def build_ivfpq(key: jax.Array, points: jax.Array, *, nlist: int, m: int,
                 cb: int = 256, kmeans_iters: int = 12, pq_iters: int = 12,
                 opq: bool = False, train_sample: Optional[int] = None
                 ) -> IVFPQIndex:
-    """Build an IVF-PQ(-OPQ) index over ``points`` (N, D)."""
+    """Build an IVF-PQ(-OPQ) index over ``points`` (N, D).
+
+    The quantizers (coarse k-means, then PQ/OPQ on the residuals) train
+    on ``train_sample`` points drawn without replacement, or on every
+    point when it is None; all points are then encoded in chunks."""
     n = points.shape[0]
     kc, kp, ks = jax.random.split(key, 3)
     train_pts = points
@@ -74,20 +103,21 @@ def build_ivfpq(key: jax.Array, points: jax.Array, *, nlist: int, m: int,
 
     km = kmeans(kc, train_pts, k=nlist, iters=kmeans_iters)
     centroids = km.centroids
-    assign, _ = assign_chunked(points.astype(jnp.float32), centroids)
-    residuals = points.astype(jnp.float32) - centroids[assign]
+    train_f = train_pts.astype(jnp.float32)
+    train_assign, _ = assign_chunked(train_f, centroids)
+    residuals = train_f - centroids[train_assign]
 
     rotation = None
     if opq:
         opq_cb: OPQCodebook = train_opq(kp, residuals, m=m, cb=cb,
                                         pq_iters=pq_iters)
         rotation = opq_cb.rotation
-        residuals = residuals @ rotation
         codebook = opq_cb.pq
     else:
         codebook = train_pq(kp, residuals, m=m, cb=cb, iters=pq_iters)
+    del train_f, residuals
 
-    codes = encode_pq(codebook, residuals)                     # (N, M)
+    assign, codes = assign_encode(points, centroids, codebook, rotation)
 
     # group by cluster: stable sort by assignment
     order = jnp.argsort(assign, stable=True)

@@ -22,6 +22,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+#: f32 GEMMs whose results rank or sum distances run at full precision
+#: (the TPU default is one bf16 pass; the CPU ignores the setting).
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class KMeansState(NamedTuple):
     centroids: jax.Array  # (K, D) f32
@@ -33,13 +37,17 @@ def l2_sq(x: jax.Array, y: jax.Array) -> jax.Array:
     """Pairwise squared L2 between rows of x (n, d) and y (m, d) -> (n, m).
 
     Uses the expansion ||x||^2 - 2 x.y + ||y||^2 (one GEMM — MXU-friendly);
-    clamped at 0 against catastrophic cancellation.
+    clamped at 0 against catastrophic cancellation.  The GEMM runs at
+    HIGHEST precision: a TPU's default single bf16 pass leaves errors
+    the size of the neighbor gaps, and the clamped ties then send whole
+    blobs to the lowest-index centroid (a 735k-point cluster out of 10M
+    in a chip run).
     """
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
     xx = jnp.sum(x * x, axis=-1, keepdims=True)          # (n, 1)
     yy = jnp.sum(y * y, axis=-1, keepdims=True).T        # (1, m)
-    d = xx + yy - 2.0 * (x @ y.T)
+    d = xx + yy - 2.0 * jnp.matmul(x, y.T, precision=HIGHEST)
     return jnp.maximum(d, 0.0)
 
 

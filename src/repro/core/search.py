@@ -8,7 +8,10 @@
 
 The distributed engine (sharded_search.py) runs the same phases with
 LC/DC/TS per shard and a final cross-shard merge.  ``use_kernels=True``
-routes LC/DC through the Pallas kernels in interpret-or-TPU mode.
+routes LC/DC through the Pallas kernels: interpreted on the CPU backend,
+compiled by Mosaic elsewhere, where only ``strategy="onehot"`` compiles
+(the kernels ran on a TPU v5e in ``chip_smoke.py``).  The served default
+stays the jnp path (``use_kernels=False``, ``strategy="gather"``).
 """
 
 from __future__ import annotations
@@ -23,7 +26,7 @@ from repro.core.kmeans import l2_sq
 from repro.core.ivf import IVFPQIndex, PaddedClusters
 from repro.core.adc import (build_lut_batch, adc_distances,
                             adc_distances_quantized, quantize_lut)
-from repro.core.topk import topk_smallest
+from repro.core.topk import smallest_k, topk_smallest
 
 
 class SearchParams(NamedTuple):
@@ -36,10 +39,11 @@ class SearchParams(NamedTuple):
 
 
 def cluster_locate(queries: jax.Array, centroids: jax.Array, nprobe: int):
-    """CL: (Q, D) x (nlist, D) -> probe ids (Q, nprobe) + centroid dists."""
-    d = l2_sq(queries, centroids)
-    nd, idx = jax.lax.top_k(-d, nprobe)
-    return idx.astype(jnp.int32), -nd
+    """CL: (Q, D) x (nlist, D) -> probe ids (Q, nprobe) + centroid dists.
+    With nprobe > nlist the surplus probes are -1 (distance +inf) and
+    scan nothing (``gather_probed``)."""
+    d, idx = smallest_k(l2_sq(queries, centroids), nprobe)
+    return idx, d
 
 
 def cluster_locate_masked(queries: jax.Array, centroids: jax.Array,
@@ -55,8 +59,18 @@ def cluster_locate_masked(queries: jax.Array, centroids: jax.Array,
     """
     d = l2_sq(queries, centroids)
     d = jnp.where(allowed, d, jnp.inf)
-    nd, idx = jax.lax.top_k(-d, nprobe)
-    return idx.astype(jnp.int32), -nd
+    d, idx = smallest_k(d, nprobe)
+    return idx, d
+
+
+def gather_probed(clusters: PaddedClusters, flat_probes: jax.Array):
+    """Codes (T, C, M), ids (T, C) and sizes (T,) of the probed clusters.
+    A -1 probe (nprobe > nlist) reads cluster 0 but gets size 0 and ids
+    -1, so it scans nothing."""
+    ok = flat_probes >= 0
+    p = jnp.where(ok, flat_probes, 0)
+    return (clusters.codes[p], jnp.where(ok[:, None], clusters.ids[p], -1),
+            jnp.where(ok, clusters.sizes[p], 0))
 
 
 def _search_chunk(queries, centroids, codebook, clusters: PaddedClusters,
@@ -69,11 +83,7 @@ def _search_chunk(queries, centroids, codebook, clusters: PaddedClusters,
     if rotation is not None:
         residual = residual @ rotation
     flat_res = residual.reshape(qc * p, -1)
-    flat_probes = probes.reshape(-1)
-    # gather the probed clusters' codes/ids/sizes
-    codes = clusters.codes[flat_probes]                           # (QcP, C, M)
-    ids = clusters.ids[flat_probes]                               # (QcP, C)
-    sizes = clusters.sizes[flat_probes]                           # (QcP,)
+    codes, ids, sizes = gather_probed(clusters, probes.reshape(-1))
     quantized = params.lut_dtype == "uint8"
     if params.use_kernels:
         from repro.kernels import ops as kops

@@ -62,7 +62,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
 
 from repro.core.ivf import IVFPQIndex, PaddedClusters
 from repro.core.pq import PQCodebook
@@ -409,7 +408,7 @@ def make_sharded_step(mesh, sindex: ShardedIndex, *, k: int,
                     sidx[0], queries, centroids)
         return bd[None], bi[None]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(), P()),
@@ -534,7 +533,7 @@ def make_sharded_step_lut(mesh, sindex: ShardedIndex, *, k: int,
                                      use_kernels=use_kernels)
         return bd[None], bi[None]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), P()),
         out_specs=(P(axis), P(axis)))

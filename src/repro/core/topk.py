@@ -1,13 +1,12 @@
-"""Top-k selection utilities (the paper's TS phase).
+"""Top-k selection utilities (the paper's TS phase, XLA path).
 
-Three layers:
-  * ``topk_smallest``           — thin lax.top_k wrapper (XLA path).
-  * ``merge_topk``              — merge two sorted top-k candidate lists
-                                  (per-shard results -> global winners).
-  * ``bitonic_merge_sorted``    — compare-exchange merge usable *inside* a
-                                  Pallas TPU kernel (no sort HLO, only
-                                  min/max/roll — VPU-friendly), used by the
-                                  fused scan+TS kernel.
+  * ``topk_smallest`` — thin lax.top_k wrapper over (dists, ids).
+  * ``smallest_k``    — lax.top_k with k clamped to the axis and padded.
+  * ``merge_topk``    — merge two sorted top-k candidate lists
+                        (per-shard results -> global winners).
+
+The fused scan kernel selects inside Pallas instead
+(``kernels/pq_scan._select_topk``).
 """
 
 from __future__ import annotations
@@ -22,100 +21,24 @@ def topk_smallest(dists: jax.Array, ids: jax.Array, k: int):
     return -neg, jnp.take_along_axis(ids, idx, axis=-1)
 
 
+def smallest_k(dists: jax.Array, k: int):
+    """k smallest along the last axis -> (values, indices), ascending.
+
+    ``k`` may exceed the axis (nprobe > nlist after clusters merge):
+    ``lax.top_k`` is asked for at most the axis length and the missing
+    slots come back as (+inf, -1)."""
+    kk = min(k, dists.shape[-1])
+    neg, idx = jax.lax.top_k(-dists, kk)
+    vals, idx = -neg, idx.astype(jnp.int32)
+    if kk < k:
+        pad = [(0, 0)] * (dists.ndim - 1) + [(0, k - kk)]
+        vals = jnp.pad(vals, pad, constant_values=jnp.inf)
+        idx = jnp.pad(idx, pad, constant_values=-1)
+    return vals, idx
+
+
 def merge_topk(d1, i1, d2, i2, k: int):
     """Merge two (…, k') candidate lists -> k smallest."""
     d = jnp.concatenate([d1, d2], axis=-1)
     i = jnp.concatenate([i1, i2], axis=-1)
     return topk_smallest(d, i, k)
-
-
-# ---------------------------------------------------------------------------
-# Bitonic primitives for in-kernel TS.  All ops are elementwise min/max plus
-# static slicing — legal inside Pallas TPU kernels (no dynamic gather, no
-# sort HLO).  Lengths must be powers of two; the fused kernel pads k and the
-# block size accordingly.
-# ---------------------------------------------------------------------------
-
-def _cas(dv, iv, stride: int, ascending: bool):
-    """One compare-and-swap stage over pairs (j, j+stride) within 2*stride
-    groups, vectorized via reshape."""
-    n = dv.shape[-1]
-    d2 = dv.reshape(*dv.shape[:-1], n // (2 * stride), 2, stride)
-    i2 = iv.reshape(*iv.shape[:-1], n // (2 * stride), 2, stride)
-    lo_d, hi_d = d2[..., 0, :], d2[..., 1, :]
-    lo_i, hi_i = i2[..., 0, :], i2[..., 1, :]
-    swap = (lo_d > hi_d) if ascending else (lo_d < hi_d)
-    new_lo_d = jnp.where(swap, hi_d, lo_d)
-    new_hi_d = jnp.where(swap, lo_d, hi_d)
-    new_lo_i = jnp.where(swap, hi_i, lo_i)
-    new_hi_i = jnp.where(swap, lo_i, hi_i)
-    dv = jnp.stack([new_lo_d, new_hi_d], axis=-2).reshape(dv.shape)
-    iv = jnp.stack([new_lo_i, new_hi_i], axis=-2).reshape(iv.shape)
-    return dv, iv
-
-
-def bitonic_sort(dv, iv, ascending: bool = True):
-    """Full bitonic sort of a power-of-two length-n vector (last axis).
-    O(log^2 n) compare-exchange stages, all static."""
-    n = dv.shape[-1]
-    assert n & (n - 1) == 0, f"bitonic length must be pow2, got {n}"
-    size = 2
-    while size <= n:
-        # make bitonic runs of `size`: sort alternating directions
-        half = size // 2
-        # descending-direction mask per group handled by flipping halves:
-        # standard network: first make bitonic by sorting pairs of runs in
-        # opposite order — implemented by reversing odd runs.
-        dv, iv = _flip_odd_runs(dv, iv, size)
-        stride = half
-        while stride >= 1:
-            dv, iv = _cas(dv, iv, stride, ascending=True)
-            stride //= 2
-        size *= 2
-    if not ascending:
-        dv = jnp.flip(dv, axis=-1)
-        iv = jnp.flip(iv, axis=-1)
-    return dv, iv
-
-
-def _flip_odd_runs(dv, iv, size: int):
-    """Reverse every odd run of length size//2... implemented as: view as
-    (groups, size) and flip the second half of each group."""
-    n = dv.shape[-1]
-    g = n // size
-    d2 = dv.reshape(*dv.shape[:-1], g, size)
-    i2 = iv.reshape(*iv.shape[:-1], g, size)
-    half = size // 2
-    d2 = jnp.concatenate([d2[..., :half], jnp.flip(d2[..., half:], -1)], -1)
-    i2 = jnp.concatenate([i2[..., :half], jnp.flip(i2[..., half:], -1)], -1)
-    return d2.reshape(dv.shape), i2.reshape(iv.shape)
-
-
-def bitonic_merge_sorted(d_a, i_a, d_b, i_b):
-    """Merge two ascending-sorted power-of-two lists into one ascending list
-    of combined length.  Classic bitonic merge: concat(a, reverse(b)) is
-    bitonic; then log2(n) CAS stages."""
-    dv = jnp.concatenate([d_a, jnp.flip(d_b, -1)], axis=-1)
-    iv = jnp.concatenate([i_a, jnp.flip(i_b, -1)], axis=-1)
-    n = dv.shape[-1]
-    assert n & (n - 1) == 0
-    stride = n // 2
-    while stride >= 1:
-        dv, iv = _cas(dv, iv, stride, ascending=True)
-        stride //= 2
-    return dv, iv
-
-
-def running_topk_update(best_d, best_i, block_d, block_i):
-    """Fold a new block of candidates into a sorted running top-k buffer.
-
-    best_d/best_i: (k,) ascending-sorted current winners.
-    block_d/block_i: (b,) unsorted new candidates, b power-of-two >= k.
-    Returns updated sorted (k,) winners.  Cost: one bitonic sort of b plus a
-    bitonic merge of 2k — the in-kernel TS phase.
-    """
-    k = best_d.shape[-1]
-    sb_d, sb_i = bitonic_sort(block_d, block_i, ascending=True)
-    merged_d, merged_i = bitonic_merge_sorted(best_d, best_i,
-                                              sb_d[..., :k], sb_i[..., :k])
-    return merged_d[..., :k], merged_i[..., :k]

@@ -17,6 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 
+_GEN_CHUNK = 1 << 18          # rows per float64 generation chunk
+
+
 class VectorDataset(NamedTuple):
     points: jax.Array        # (N, D) uint8 or f32
     queries: jax.Array       # (Q, D) same dtype
@@ -39,7 +42,41 @@ def make_clustered_corpus(seed: int, n: int, d: int, *, n_queries: int = 256,
     alpha = np.full(n_components, 1.0 / max(size_skew, 1e-3))
     weights = rng.dirichlet(alpha)
     comp = rng.choice(n_components, size=n, p=weights)
-    pts = centers[comp] + rng.normal(0.0, 12.0, size=(n, d))
+
+    # Points are drawn in row chunks so the float64 working set is one
+    # chunk, not (N, D); the generator's stream is consumed in the same
+    # order, so the corpus is identical to a single draw.  uint8 scaling
+    # needs the global range first: pass 1 finds it, pass 2 replays the
+    # same stream from the saved state and quantizes.
+    buf = np.empty((min(n, _GEN_CHUNK), d))
+    mean = np.empty_like(buf)
+
+    def chunks():      # normal(0, 12) is 0 + 12 * standard_normal in numpy
+        for s in range(0, n, _GEN_CHUNK):
+            rows = comp[s:s + _GEN_CHUNK]
+            p, c = buf[:len(rows)], mean[:len(rows)]
+            rng.standard_normal(out=p)
+            p *= 12.0
+            np.take(centers, rows, axis=0, out=c)
+            np.add(c, p, out=p)
+            yield s, p
+
+    out = np.empty((n, d), np.uint8 if dtype == jnp.uint8 else np.float32)
+    if dtype == jnp.uint8:
+        state = rng.bit_generator.state
+        lo, hi = np.inf, -np.inf
+        for _, p in chunks():
+            lo, hi = min(lo, p.min()), max(hi, p.max())
+        rng.bit_generator.state = state
+        scale = 255.0 / (hi - lo)
+        for s, p in chunks():
+            p -= lo
+            p *= scale
+            np.round(p, out=p)
+            out[s:s + len(p)] = np.clip(p, 0, 255, out=p)
+    else:
+        for s, p in chunks():
+            out[s:s + len(p)] = p
 
     # Zipfian query component choice over components ranked by weight
     rank = np.argsort(-weights)
@@ -48,13 +85,10 @@ def make_clustered_corpus(seed: int, n: int, d: int, *, n_queries: int = 256,
     qcomp = rank[rng.choice(n_components, size=n_queries, p=zipf_p)]
     qs = centers[qcomp] + rng.normal(0.0, 12.0, size=(n_queries, d))
 
+    pts = out
     if dtype == jnp.uint8:
-        lo, hi = pts.min(), pts.max()
-        scale = 255.0 / (hi - lo)
-        pts = np.clip(np.round((pts - lo) * scale), 0, 255).astype(np.uint8)
         qs = np.clip(np.round((qs - lo) * scale), 0, 255).astype(np.uint8)
     else:
-        pts = pts.astype(np.float32)
         qs = qs.astype(np.float32)
 
     gt = np.zeros((n_queries, max(k_gt, 1)), np.int32)

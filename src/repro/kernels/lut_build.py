@@ -3,22 +3,26 @@
 For every task t (a (query, probe) pair) and subspace m:
 
     lut[t, m, cb] = || res[t, m, :] - codebook[m, cb, :] ||^2
-                  = ||res||^2 + ||C||^2 - 2 * res . C^T      (MXU dot)
+                  = ||res_m||^2 + ||C||^2 - 2 * res_m . C^T
 
-Grid  : (T / bT, M)   — both axes parallel (no cross-iteration state)
-Blocks: res       (bT, 1, dsub)   VMEM
-        codebooks (1, CB, dsub)   VMEM (per-m slice, reused across the T axis)
-        sqnorms   (1, CB)         VMEM
-        out       (bT, 1, CB)     VMEM
+All M subspaces are computed in one pass as two (bT, D) x (D, M*CB) MXU
+matmuls against block-diagonal operands built once per call:
 
-VMEM budget per step (bT=128, CB=256, dsub=8, f32):
-  res 4 KB + codebook 8 KB + out 128 KB ≈ 140 KB — far below the ~16 MB
-  VMEM of a v5e core; bT can grow to amortize grid overhead (ops.py default
-  bT=128 keeps the out tile at one (8,128)-tile stack of 32).
+    W[m*dsub + j, m*CB + c] = codebook[m, c, j]   -> cross = res @ W
+    S[m*dsub + j, m*CB + c] = 1                   -> rsq   = (res*res) @ S
 
-The cross term res @ C^T has MXU-aligned contractions when dsub >= 8; for the
-paper's SIFT configs (dsub = 128/M in {8, 16}) the matmul is (bT x dsub) x
-(dsub x CB) — a thin GEMM the MXU pipelines well across the M grid axis.
+so the output row (M*CB lanes) is lane-dense and no in-kernel reshape or
+sub-128-lane block is needed (Mosaic's (8, 128) block rule).
+
+Grid  : (T / bT,)  — parallel (no cross-iteration state)
+Blocks: res  (bT, D)        VMEM
+        W, S (D, M*CB)      VMEM, same block every step (fetched once)
+        sqn  (1, M*CB)      VMEM
+        out  (bT, M*CB)     VMEM
+
+VMEM per step (bT=64, D=128, M*CB=4096, f32): W + S 4 MB (double-buffered
+8 MB) + out 1 MB (2 MB) + ~3 MB of temporaries — under the 16 MB scoped
+default of a v5e core.
 """
 
 from __future__ import annotations
@@ -28,106 +32,122 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-
-def _lut_block(res_ref, cb_ref, sqn_ref) -> jax.Array:
-    r = res_ref[:, 0, :]                                  # (bT, dsub) f32
-    c = cb_ref[0]                                         # (CB, dsub) f32
-    cross = jnp.dot(r, c.T, preferred_element_type=jnp.float32)   # (bT, CB)
-    rsq = jnp.sum(r * r, axis=-1, keepdims=True)          # (bT, 1)
-    return jnp.maximum(rsq + sqn_ref[0][None, :] - 2.0 * cross, 0.0)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _lut_build_kernel(res_ref, cb_ref, sqn_ref, out_ref):
-    out_ref[:, 0, :] = _lut_block(res_ref, cb_ref, sqn_ref)
+def _block_diag(codebooks: jax.Array):
+    """codebooks (M, CB, dsub) -> W, S (M*dsub, M*CB) f32 (see header)."""
+    m, cbn, dsub = codebooks.shape
+    eye = jnp.eye(m, dtype=jnp.float32)
+    w = jnp.einsum("mcj,mn->mjnc", codebooks.astype(jnp.float32), eye)
+    s = jnp.einsum("mn,j,c->mjnc", eye, jnp.ones((dsub,), jnp.float32),
+                   jnp.ones((cbn,), jnp.float32))
+    return (w.reshape(m * dsub, m * cbn), s.reshape(m * dsub, m * cbn))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_t", "interpret"))
-def lut_build_pallas(residuals: jax.Array, codebooks: jax.Array,
-                     sqnorms: jax.Array, *, block_t: int = 128,
-                     interpret: bool = True) -> jax.Array:
-    """residuals (T, M, dsub) f32, codebooks (M, CB, dsub), sqnorms (M, CB)
-    -> luts (T, M, CB) f32.  T must be a multiple of block_t (ops.py pads)."""
-    t, m, dsub = residuals.shape
-    _, cbn, _ = codebooks.shape
+def _lut_block(res_ref, w_ref, s_ref, sqn_ref) -> jax.Array:
+    r = res_ref[...]                                       # (bT, D) f32
+    cross = jnp.dot(r, w_ref[...], precision=_HIGHEST,
+                    preferred_element_type=jnp.float32)    # (bT, M*CB)
+    rsq = jnp.dot(r * r, s_ref[...], precision=_HIGHEST,
+                  preferred_element_type=jnp.float32)
+    return jnp.maximum(rsq + sqn_ref[...] - 2.0 * cross, 0.0)
+
+
+def _lut_build_kernel(res_ref, w_ref, s_ref, sqn_ref, out_ref):
+    out_ref[...] = _lut_block(res_ref, w_ref, s_ref, sqn_ref)
+
+
+def _call(kernel, residuals, codebooks, sqnorms, out_specs, out_shape,
+          block_t, interpret, name):
+    t, d = residuals.shape
+    m, cbn, _ = codebooks.shape
     assert t % block_t == 0, (t, block_t)
-    grid = (t // block_t, m)
+    w, s = _block_diag(codebooks)
+    const = lambda i: (0, 0)                               # noqa: E731
     return pl.pallas_call(
-        _lut_build_kernel,
-        grid=grid,
+        kernel,
+        grid=(t // block_t,),
         in_specs=[
-            pl.BlockSpec((block_t, 1, dsub), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, cbn, dsub), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, cbn), lambda i, j: (j, 0)),
+            pl.BlockSpec((block_t, d), lambda i: (i, 0)),
+            pl.BlockSpec((d, m * cbn), const),
+            pl.BlockSpec((d, m * cbn), const),
+            pl.BlockSpec((1, m * cbn), const),
         ],
-        out_specs=pl.BlockSpec((block_t, 1, cbn), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, m, cbn), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-        name="drim_lut_build",
-    )(residuals.astype(jnp.float32), codebooks.astype(jnp.float32),
-      sqnorms.astype(jnp.float32))
+        name=name,
+    )(residuals.astype(jnp.float32), w, s,
+      sqnorms.astype(jnp.float32).reshape(1, m * cbn))
+
+
+@functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
+def lut_build_pallas(residuals: jax.Array, codebooks: jax.Array,
+                     sqnorms: jax.Array, *, block_t: int,
+                     interpret: bool) -> jax.Array:
+    """residuals (T, D) f32, codebooks (M, CB, dsub), sqnorms (M, CB)
+    -> luts (T, M*CB) f32.  T must be a multiple of block_t (ops.py pads)."""
+    t = residuals.shape[0]
+    m, cbn, _ = codebooks.shape
+    return _call(_lut_build_kernel, residuals, codebooks, sqnorms,
+                 pl.BlockSpec((block_t, m * cbn), lambda i: (i, 0)),
+                 jax.ShapeDtypeStruct((t, m * cbn), jnp.float32),
+                 block_t, interpret, "drim_lut_build")
 
 
 # --------------------------------------------------------------------------
 # Fused quantize epilogue: LC + per-(task, subspace) affine uint8
 # quantization in one kernel.  The f32 table exists only inside the VMEM
-# block; HBM sees (bT, 1, CB) u8 plus two (bT, 1) f32 scalars — the
+# block; HBM sees (bT, M*CB) u8 plus two (bT, M) f32 tables — the
 # writeback drops ~4x (the paper's shrink-the-LUT move applied to our
-# own memory hierarchy).  Quantization math matches core.adc.quantize_lut
-# exactly (same ops, same order), so host- and kernel-quantized tables
-# agree bit-for-bit on identical f32 inputs.
+# own memory hierarchy).  Quantization math follows core.adc.quantize_lut
+# (same ops, same order); an entry on a rounding boundary may differ by
+# one count where the chip's division rounds differently.
 # --------------------------------------------------------------------------
 
-def _lut_build_q_kernel(res_ref, cb_ref, sqn_ref, outq_ref, outs_ref,
+def _lut_build_q_kernel(res_ref, w_ref, s_ref, sqn_ref, outq_ref, outs_ref,
                         outb_ref):
-    lut = _lut_block(res_ref, cb_ref, sqn_ref)            # (bT, CB) f32
-    lo = jnp.min(lut, axis=-1)                            # (bT,)
-    hi = jnp.max(lut, axis=-1)
-    scale = jnp.where(hi > lo, (hi - lo) / 255.0, 1.0)
-    q = jnp.round((lut - lo[:, None]) / scale[:, None])
-    outq_ref[:, 0, :] = jnp.clip(q, 0.0, 255.0).astype(jnp.uint8)
-    outs_ref[:, 0] = scale
-    outb_ref[:, 0] = lo
+    lut = _lut_block(res_ref, w_ref, s_ref, sqn_ref)       # (bT, M*CB) f32
+    m = outs_ref.shape[1]
+    cbn = lut.shape[1] // m
+    lane = jax.lax.broadcasted_iota(jnp.int32, outs_ref.shape, 1)
+    scales = jnp.zeros(outs_ref.shape, jnp.float32)
+    biases = jnp.zeros(outs_ref.shape, jnp.float32)
+    for mm in range(m):                           # static unroll over subspaces
+        seg = lut[:, mm * cbn:(mm + 1) * cbn]              # (bT, CB)
+        lo = jnp.min(seg, axis=1, keepdims=True)           # (bT, 1)
+        hi = jnp.max(seg, axis=1, keepdims=True)
+        scale = jnp.where(hi > lo, (hi - lo) / 255.0, 1.0)
+        q = jnp.clip(jnp.round((seg - lo) / scale), 0.0, 255.0)
+        outq_ref[:, mm * cbn:(mm + 1) * cbn] = (
+            q.astype(jnp.int32).astype(jnp.uint8))
+        scales = jnp.where(lane == mm, scale, scales)
+        biases = jnp.where(lane == mm, lo, biases)
+    outs_ref[...] = scales
+    outb_ref[...] = biases
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_t", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
 def lut_build_q_pallas(residuals: jax.Array, codebooks: jax.Array,
-                       sqnorms: jax.Array, *, block_t: int = 128,
-                       interpret: bool = True):
-    """residuals (T, M, dsub) f32, codebooks (M, CB, dsub), sqnorms (M, CB)
-    -> (lut_q (T, M, CB) u8, scale (T, M) f32, bias (T, M) f32).
+                       sqnorms: jax.Array, *, block_t: int,
+                       interpret: bool):
+    """residuals (T, D) f32, codebooks (M, CB, dsub), sqnorms (M, CB)
+    -> (lut_q (T, M*CB) u8, scale (T, M) f32, bias (T, M) f32).
     T must be a multiple of block_t (ops.py pads)."""
-    t, m, dsub = residuals.shape
-    _, cbn, _ = codebooks.shape
-    assert t % block_t == 0, (t, block_t)
-    grid = (t // block_t, m)
-    return pl.pallas_call(
-        _lut_build_q_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_t, 1, dsub), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, cbn, dsub), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, cbn), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_t, 1, cbn), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((block_t, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((block_t, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((t, m, cbn), jnp.uint8),
-            jax.ShapeDtypeStruct((t, m), jnp.float32),
-            jax.ShapeDtypeStruct((t, m), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-        name="drim_lut_build_q",
-    )(residuals.astype(jnp.float32), codebooks.astype(jnp.float32),
-      sqnorms.astype(jnp.float32))
+    t = residuals.shape[0]
+    m, cbn, _ = codebooks.shape
+    row = lambda i: (i, 0)                                 # noqa: E731
+    return _call(
+        _lut_build_q_kernel, residuals, codebooks, sqnorms,
+        [pl.BlockSpec((block_t, m * cbn), row),
+         pl.BlockSpec((block_t, m), row),
+         pl.BlockSpec((block_t, m), row)],
+        [jax.ShapeDtypeStruct((t, m * cbn), jnp.uint8),
+         jax.ShapeDtypeStruct((t, m), jnp.float32),
+         jax.ShapeDtypeStruct((t, m), jnp.float32)],
+        block_t, interpret, "drim_lut_build_q")

@@ -1,31 +1,43 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles: dtype casts, padding to block multiples, k padding to a power of
-two, strategy/backend selection.  ``interpret`` defaults to True off-TPU
-(this container) and False on real TPU devices.
+Handles: dtype casts, the kernels' layouts (flattened LUT rows, codes
+transposed so the candidate axis is the lane axis), padding to block
+multiples, and backend selection.  ``interpret`` defaults to True only on
+the CPU backend; everywhere else the kernels are compiled by Mosaic, and
+the ``onehot`` strategy is the one that compiles for the TPU.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.adc import QuantizedLUT
 from repro.kernels.lut_build import lut_build_pallas, lut_build_q_pallas
-from repro.kernels.pq_scan import (pq_scan_dc_pallas, pq_scan_dc_q_pallas,
-                                   pq_scan_topk_pallas, pq_scan_topk_q_pallas)
+from repro.kernels.pq_scan import pq_scan_dc_pallas, pq_scan_topk_pallas
 from repro.util import next_pow2
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
-# The onehot intermediate (bC, M*CB) dominates the scan's VMEM footprint
-# (pq_scan.py header); quantized LUTs build it in bf16 instead of f32, so
-# the same budget fits twice the block — u8 defaults to 2x the f32 block.
+def _resolve_interpret(interpret: bool | None, strategy: str = "onehot"
+                       ) -> bool:
+    if interpret is None:
+        interpret = _default_interpret()
+    if strategy == "gather" and not interpret:
+        raise ValueError(
+            "strategy='gather' runs only in interpret mode: Mosaic (the TPU "
+            "kernel compiler) has no vector gather for the per-subspace "
+            "table lookup.  Use strategy='onehot' on the chip, or "
+            "interpret=True.")
+    return interpret
+
+
+# Per-block VMEM is one (CB, bC) onehot per subspace (pq_scan.py header);
+# quantized LUTs build it in bf16 instead of f32, so the same budget fits
+# twice the block — u8 defaults to 2x the f32 block.
 _BLOCK_C_F32 = 256
 _BLOCK_C_U8 = 512
 
@@ -36,43 +48,60 @@ def _resolve_block_c(block_c: int | None, quantized: bool) -> int:
     return _BLOCK_C_U8 if quantized else _BLOCK_C_F32
 
 
-def lut_build(residuals: jax.Array, codebooks: jax.Array,
-              sqnorms: jax.Array, *, block_t: int = 128,
-              interpret: bool | None = None) -> jax.Array:
-    """(T, D) residuals -> (T, M, CB) LUTs (pads T to block_t multiple)."""
-    if interpret is None:
-        interpret = _default_interpret()
+def _pad_rows(residuals: jax.Array, block_t: int):
     t = residuals.shape[0]
-    m, cbn, dsub = codebooks.shape
-    res = residuals.reshape(t, m, dsub)
     bt = min(block_t, next_pow2(t))
     pad = (-t) % bt
     if pad:
-        res = jnp.pad(res, ((0, pad), (0, 0), (0, 0)))
+        residuals = jnp.pad(residuals, ((0, pad), (0, 0)))
+    return residuals, bt
+
+
+def lut_build(residuals: jax.Array, codebooks: jax.Array,
+              sqnorms: jax.Array, *, block_t: int = 64,
+              interpret: bool | None = None) -> jax.Array:
+    """(T, D) residuals -> (T, M, CB) LUTs (pads T to block_t multiple)."""
+    interpret = _resolve_interpret(interpret)
+    t = residuals.shape[0]
+    m, cbn, _ = codebooks.shape
+    res, bt = _pad_rows(residuals, block_t)
     out = lut_build_pallas(res, codebooks, sqnorms, block_t=bt,
                            interpret=interpret)
-    return out[:t]
+    return out[:t].reshape(t, m, cbn)
 
 
 def lut_build_q(residuals: jax.Array, codebooks: jax.Array,
-                sqnorms: jax.Array, *, block_t: int = 128,
+                sqnorms: jax.Array, *, block_t: int = 64,
                 interpret: bool | None = None) -> QuantizedLUT:
     """LC with the fused quantize epilogue: (T, D) residuals ->
     QuantizedLUT of (T, M, CB) u8 + (T, M) scale/bias.  The f32 table
     never leaves the kernel's VMEM block — HBM writeback is the u8 table
     plus two scalars per subspace (~4x less than ``lut_build``)."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _resolve_interpret(interpret)
     t = residuals.shape[0]
-    m, cbn, dsub = codebooks.shape
-    res = residuals.reshape(t, m, dsub)
-    bt = min(block_t, next_pow2(t))
-    pad = (-t) % bt
-    if pad:
-        res = jnp.pad(res, ((0, pad), (0, 0), (0, 0)))
+    m, cbn, _ = codebooks.shape
+    res, bt = _pad_rows(residuals, block_t)
     lut_q, scale, bias = lut_build_q_pallas(res, codebooks, sqnorms,
                                             block_t=bt, interpret=interpret)
-    return QuantizedLUT(lut_q[:t], scale[:t], bias[:t])
+    return QuantizedLUT(lut_q[:t].reshape(t, m, cbn), scale[:t], bias[:t])
+
+
+def _scan_operands(lut, codes: jax.Array, block_c: int):
+    """Kernel-layout LUT operands and lane-major codes (T, M, C') padded
+    to a multiple of the block; returns (lut, scale, bias), codes, cb."""
+    t, c, m = codes.shape
+    if isinstance(lut, QuantizedLUT):
+        cbn = lut.lut_q.shape[-1]
+        luts = (lut.lut_q.astype(jnp.uint8).reshape(t, 1, m * cbn),
+                lut.scale.reshape(t, 1, m), lut.bias.reshape(t, 1, m))
+    else:
+        cbn = lut.shape[-1]
+        luts = (lut.reshape(t, 1, m * cbn), None, None)
+    codes_t = jnp.swapaxes(codes.astype(jnp.int32), 1, 2)
+    pad = (-c) % block_c
+    if pad:
+        codes_t = jnp.pad(codes_t, ((0, 0), (0, 0), (0, pad)))
+    return luts, codes_t, cbn
 
 
 def pq_scan_dc(lut, codes: jax.Array, sizes: jax.Array | None
@@ -83,22 +112,13 @@ def pq_scan_dc(lut, codes: jax.Array, sizes: jax.Array | None
 
     ``lut`` is either the f32 (T, M, CB) table or a
     :class:`~repro.core.adc.QuantizedLUT` (uint8 fast path)."""
-    if interpret is None:
-        interpret = _default_interpret()
-    quantized = isinstance(lut, QuantizedLUT)
+    interpret = _resolve_interpret(interpret, strategy)
     t, c, m = codes.shape
-    bc = min(_resolve_block_c(block_c, quantized), next_pow2(c))
-    pad = (-c) % bc
-    codes_i = codes.astype(jnp.int32)
-    if pad:
-        codes_i = jnp.pad(codes_i, ((0, 0), (0, pad), (0, 0)))
-    if quantized:
-        d = pq_scan_dc_q_pallas(lut.lut_q, lut.scale, lut.bias, codes_i,
-                                strategy=strategy, block_c=bc,
-                                interpret=interpret)[:, :c]
-    else:
-        d = pq_scan_dc_pallas(lut, codes_i, strategy=strategy, block_c=bc,
-                              interpret=interpret)[:, :c]
+    bc = min(_resolve_block_c(block_c, isinstance(lut, QuantizedLUT)),
+             next_pow2(c))
+    luts, codes_t, cbn = _scan_operands(lut, codes, bc)
+    d = pq_scan_dc_pallas(*luts, codes_t, cb=cbn, strategy=strategy,
+                          block_c=bc, interpret=interpret)[:, 0, :c]
     if sizes is not None:
         valid = jnp.arange(c)[None, :] < sizes[:, None]
         d = jnp.where(valid, d, jnp.inf)
@@ -112,25 +132,16 @@ def pq_scan_topk(lut, codes: jax.Array, ids: jax.Array,
 
     ``lut`` is either the f32 (T, M, CB) table or a
     :class:`~repro.core.adc.QuantizedLUT` (uint8 fast path)."""
-    if interpret is None:
-        interpret = _default_interpret()
-    quantized = isinstance(lut, QuantizedLUT)
+    interpret = _resolve_interpret(interpret, strategy)
     t, c, m = codes.shape
-    k_pad = next_pow2(max(k, 8))
-    bc = max(min(_resolve_block_c(block_c, quantized), next_pow2(c)), k_pad)
-    pad = (-c) % bc
-    codes_i = codes.astype(jnp.int32)
+    bc = min(_resolve_block_c(block_c, isinstance(lut, QuantizedLUT)),
+             next_pow2(c))
+    luts, codes_t, cbn = _scan_operands(lut, codes, bc)
     ids_i = ids.astype(jnp.int32)
+    pad = codes_t.shape[2] - c
     if pad:
-        codes_i = jnp.pad(codes_i, ((0, 0), (0, pad), (0, 0)))
         ids_i = jnp.pad(ids_i, ((0, 0), (0, pad)), constant_values=-1)
-    if quantized:
-        bd, bi = pq_scan_topk_q_pallas(lut.lut_q, lut.scale, lut.bias,
-                                       codes_i, ids_i, sizes, k_pad=k_pad,
-                                       strategy=strategy, block_c=bc,
-                                       interpret=interpret)
-    else:
-        bd, bi = pq_scan_topk_pallas(lut, codes_i, ids_i, sizes, k_pad=k_pad,
-                                     strategy=strategy, block_c=bc,
-                                     interpret=interpret)
-    return bd[:, :k], bi[:, :k]
+    bd, bi = pq_scan_topk_pallas(*luts, codes_t, ids_i[:, None, :], sizes,
+                                 k=k, cb=cbn, strategy=strategy, block_c=bc,
+                                 interpret=interpret)
+    return bd[:, 0, :k], bi[:, 0, :k]

@@ -224,8 +224,7 @@ def run_drim_ann_cell(multi_pod: bool, out_dir: pathlib.Path = ART_DIR,
                                  lut_dtype=lut_dtype)
         return bd[None], bi[None]
 
-    from repro.core.compat import shard_map
-    smap = shard_map(
+    smap = jax.shard_map(
         search_step, mesh=mesh,
         in_specs=(P(shard_axes), P(shard_axes), P(shard_axes), P(shard_axes),
                   P(shard_axes), P(shard_axes), P(), P(), P(), P()),

@@ -43,6 +43,7 @@ import numpy as np
 from repro.configs import registry
 from repro.models import (init_params, forward, encode, init_caches,
                           decode_step)
+from repro.util import enable_compile_cache
 
 
 def generate(cfg, params, prompts: jax.Array, gen_len: int,
@@ -217,6 +218,7 @@ def main():
                     help="stream driver: discrete-event simulation or "
                          "wall-clock executor-backed replicas")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.ann:
         serve_ann(args)
         return

@@ -57,7 +57,8 @@ from repro.core.coarse2 import Coarse2, coarse2_locate
 from repro.core.filter import NO_TAG, VectorMeta, mask_scoped_distances
 from repro.core.ivf import IVFPQIndex, PaddedClusters
 from repro.core.search import (SearchParams, cluster_locate,
-                               cluster_locate_masked, search_ivfpq)
+                               cluster_locate_masked, gather_probed,
+                               search_ivfpq)
 from repro.core.topk import topk_smallest
 from repro.runtime.batching import (BucketPolicy, MicroBatch, MicroBatcher,
                                     Request)
@@ -139,9 +140,7 @@ def _dc_ts(lut, flat_probes, clusters: PaddedClusters, *, k: int,
            strategy: str, nprobe: int):
     """DC + TS over cache-assembled LUTs: (Q*P, M, CB) f32 — or a
     (Q*P,)-batched QuantizedLUT on the uint8 path — -> (Q, k) x2."""
-    codes = clusters.codes[flat_probes]
-    ids = clusters.ids[flat_probes]
-    sizes = clusters.sizes[flat_probes]
+    codes, ids, sizes = gather_probed(clusters, flat_probes)
     strat = "gather" if strategy == "gather" else "onehot"
     if isinstance(lut, QuantizedLUT):
         dists = adc_distances_quantized(lut, codes, sizes, strat)
@@ -217,9 +216,7 @@ def _dc_ts_scoped(lut, flat_probes, clusters: PaddedClusters, meta_tenant,
     predicate mask strikes out-of-scope candidate rows to ``+inf`` (and
     id -1) before TS — the same discipline the sizes mask uses, so
     filtered top-k is exact over the matching rows."""
-    codes = clusters.codes[flat_probes]
-    ids = clusters.ids[flat_probes]
-    sizes = clusters.sizes[flat_probes]
+    codes, ids, sizes = gather_probed(clusters, flat_probes)
     strat = "gather" if strategy == "gather" else "onehot"
     if isinstance(lut, QuantizedLUT):
         dists = adc_distances_quantized(lut, codes, sizes, strat)
@@ -286,9 +283,7 @@ def _scoped_search_fused(queries, centroids, rotation, codebook,
     if lut_u8:
         lut = quantize_lut(lut)
     flat_probes = probes.reshape(-1)
-    codes = clusters.codes[flat_probes]
-    ids = clusters.ids[flat_probes]
-    sizes = clusters.sizes[flat_probes]
+    codes, ids, sizes = gather_probed(clusters, flat_probes)
     strat = "gather" if strategy == "gather" else "onehot"
     if lut_u8:
         dists = adc_distances_quantized(lut, codes, sizes, strat)

@@ -378,6 +378,8 @@ def main() -> int:
                     help="autotune: persist the winning ServiceSpec as a "
                          "deploy file (.json/.yaml)")
     args = ap.parse_args()
+    from repro.util import enable_compile_cache
+    enable_compile_cache()
     if args.selftest_chaos:
         from repro.service.chaos import selftest_chaos
         return selftest_chaos(seed=args.chaos_seed,

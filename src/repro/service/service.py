@@ -284,7 +284,8 @@ class AnnService:
             p, _ = cluster_locate(
                 jnp.asarray(np.asarray(q, np.float32)[None]),
                 handle.centroids, spec.nprobe)
-            return np.asarray(p)[0]
+            p = np.asarray(p)[0]
+            return p[p >= 0]            # -1: nprobe > nlist after merges
 
         svc = cls.__new__(cls)
         router = Router(policy, spec.replicas,

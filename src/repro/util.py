@@ -20,6 +20,26 @@ def next_pow2(x: int) -> int:
     return 1 << (max(int(x), 1) - 1).bit_length()
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Called by the entry points (``chip_smoke.py``, ``python -m
+    repro.service``, ``python -m repro.launch.serve``,
+    ``benchmarks/run.py``), never on import.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing
+    else is set here.  Otherwise the cache lives at ``<checkout>/.jax_cache``
+    — a fixed path, because the path is part of the cache key."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def fsync_dir(path) -> None:
     """fsync a directory so a rename/replace inside it is durable.
 

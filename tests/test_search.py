@@ -29,6 +29,27 @@ def test_cluster_locate_shapes(small_index, small_corpus):
     assert (np.diff(np.asarray(dists), axis=1) >= -1e-3).all()
 
 
+@pytest.mark.parametrize("lut_dtype", ["f32", "uint8"])
+def test_nprobe_above_nlist_scans_every_cluster_once(small_index,
+                                                    small_clusters,
+                                                    small_corpus, lut_dtype):
+    """nprobe > nlist (a live index after merges) searches, and the surplus
+    -1 probes scan nothing: same results as nprobe == nlist."""
+    q = small_corpus.queries.astype(jnp.float32)
+    nlist = small_index.nlist
+    probes, dists = cluster_locate(q, small_index.centroids, nprobe=nlist + 5)
+    assert (np.asarray(probes)[:, nlist:] == -1).all()
+    assert np.isinf(np.asarray(dists)[:, nlist:]).all()
+    over = search_ivfpq(small_index, small_clusters, q,
+                        SearchParams(nprobe=nlist + 5, k=10,
+                                     lut_dtype=lut_dtype))
+    exact = search_ivfpq(small_index, small_clusters, q,
+                         SearchParams(nprobe=nlist, k=10,
+                                      lut_dtype=lut_dtype))
+    np.testing.assert_array_equal(np.asarray(over[1]), np.asarray(exact[1]))
+    np.testing.assert_allclose(np.asarray(over[0]), np.asarray(exact[0]))
+
+
 def test_recall_constraint_paper(small_index, small_clusters, small_corpus):
     """Paper §V-A: all experiments under recall@10 >= 0.8 — reproduce it."""
     p = SearchParams(nprobe=16, k=10, query_chunk=64)

@@ -144,6 +144,9 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
 def test_shard_map_8dev_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # the parent already loaded JAX; on a TPU host it holds the chip, so
+    # the child must stay on the CPU or it blocks on the device lock
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", SHARD_MAP_SCRIPT],
                          capture_output=True, text=True, env=env,
                          cwd=os.path.dirname(os.path.dirname(
