@@ -1,0 +1,9 @@
+"""Search step, TS: device time of the leaf operations named by the
+program's ``TS`` scope in the traced window (``phases.py``) per query
+the window completed, in ms."""
+
+import phases
+
+
+def read(rec):
+    return phases.ms_per_query(rec, "TS")

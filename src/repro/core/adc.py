@@ -51,6 +51,7 @@ def build_lut(codebook: PQCodebook, residual: jax.Array) -> jax.Array:
     return jnp.maximum(rsq + codebook.sqnorms - 2.0 * cross, 0.0)
 
 
+@jax.named_scope("LC")
 def build_lut_batch(codebook: PQCodebook, residuals: jax.Array) -> jax.Array:
     """(T, D) residuals -> (T, M, CB) LUTs (vmapped LC)."""
     return jax.vmap(lambda r: build_lut(codebook, r))(residuals)
@@ -91,6 +92,7 @@ def scan_codes_onehot(lut: jax.Array, codes: jax.Array,
                       precision=HIGHEST)
 
 
+@jax.named_scope("DC")
 def adc_distances(lut: jax.Array, codes: jax.Array, sizes: jax.Array | None
                   = None, strategy: str = "gather") -> jax.Array:
     """Batched DC over padded clusters.
@@ -129,6 +131,7 @@ class QuantizedLUT(NamedTuple):
     bias: jax.Array
 
 
+@jax.named_scope("LC")
 def quantize_lut(lut: jax.Array) -> QuantizedLUT:
     """Affine uint8 quantization over the CB axis, per (task, subspace).
 
@@ -190,6 +193,7 @@ def scan_codes_onehot_quantized(qlut: QuantizedLUT,
             + jnp.sum(qlut.bias))
 
 
+@jax.named_scope("DC")
 def adc_distances_quantized(qlut: QuantizedLUT, codes: jax.Array,
                             sizes: jax.Array | None = None,
                             strategy: str = "gather") -> jax.Array:

@@ -78,6 +78,7 @@ def build_coarse2(key, centroids, n_groups: Optional[int] = None,
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "nprobe1"))
+@jax.named_scope("CL")
 def coarse2_locate(coarse: Coarse2, queries: jax.Array, *, nprobe: int,
                    nprobe1: int):
     """Two-level CL: (Q, D) -> probe ids (Q, nprobe) + centroid dists.

@@ -7,11 +7,24 @@
     TS  top-k sorting         lax.top_k merge
 
 The distributed engine (sharded_search.py) runs the same phases with
-LC/DC/TS per shard and a final cross-shard merge.  ``use_kernels=True``
-routes LC/DC through the Pallas kernels: interpreted on the CPU backend,
-compiled by Mosaic elsewhere, where only ``strategy="onehot"`` compiles
-(the kernels ran on a TPU v5e in ``chip_smoke.py``).  The served default
-stays the jnp path (``use_kernels=False``, ``strategy="gather"``).
+LC/DC/TS per shard and a final cross-shard merge.
+
+Each phase's function carries its ``jax.named_scope`` where it is
+defined (``CL``: ``cluster_locate``, ``cluster_locate_masked``,
+``coarse2_locate``; ``RC``: ``residuals``; ``LC``: ``build_lut_batch``,
+``quantize_lut``, the ``lut_build`` kernels; ``DC``: ``gather_probed``,
+``adc_distances``, ``adc_distances_quantized``, the scan kernels; ``TS``:
+``topk_smallest``), so every jit that calls them names its ops by phase
+in the HLO ``op_name`` path, and a profiler trace can be split by phase.
+Where one scope nests inside another (``merge_topk`` inside a fused scan,
+say), the outermost phase in the ``op_name`` path owns the op.  The
+scopes are metadata only: the compiled code is the same without them.
+
+``use_kernels=True`` routes LC/DC through the Pallas kernels: interpreted
+on the CPU backend, compiled by Mosaic elsewhere, where only
+``strategy="onehot"`` compiles (the kernels ran on a TPU v5e in
+``chip_smoke.py``).  The served default stays the jnp path
+(``use_kernels=False``, ``strategy="gather"``).
 """
 
 from __future__ import annotations
@@ -28,6 +41,15 @@ from repro.core.adc import (build_lut_batch, adc_distances,
                             adc_distances_quantized, quantize_lut)
 from repro.core.topk import smallest_k, topk_smallest
 
+# The phase scopes live only in HLO metadata, which JAX's persistent
+# compilation cache leaves out of its key by default: an executable built
+# from the same computation without the scopes would then be handed back,
+# and a profile of it would name no phase.  Keep metadata in the key, with
+# source paths cut to their file names so that where a checkout lies does
+# not change it.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+jax.config.update("jax_hlo_source_file_canonicalization_regex", r".*/")
+
 
 class SearchParams(NamedTuple):
     nprobe: int
@@ -38,6 +60,7 @@ class SearchParams(NamedTuple):
     lut_dtype: str = "f32"          # "f32" | "uint8" quantized-LUT fast path
 
 
+@jax.named_scope("CL")
 def cluster_locate(queries: jax.Array, centroids: jax.Array, nprobe: int):
     """CL: (Q, D) x (nlist, D) -> probe ids (Q, nprobe) + centroid dists.
     With nprobe > nlist the surplus probes are -1 (distance +inf) and
@@ -46,6 +69,7 @@ def cluster_locate(queries: jax.Array, centroids: jax.Array, nprobe: int):
     return idx, d
 
 
+@jax.named_scope("CL")
 def cluster_locate_masked(queries: jax.Array, centroids: jax.Array,
                           nprobe: int, allowed: jax.Array):
     """CL over a per-query cluster mask (tenant namespaces, PR 10).
@@ -63,6 +87,18 @@ def cluster_locate_masked(queries: jax.Array, centroids: jax.Array,
     return idx, d
 
 
+@jax.named_scope("RC")
+def residuals(queries: jax.Array, centroids: jax.Array, rotation,
+              probes: jax.Array) -> jax.Array:
+    """RC: (Q, D) queries and (Q, P) probes -> (Q*P, D) residuals
+    ``query - centroid[probe]``, rotated where the index has a rotation."""
+    residual = queries[:, None, :] - centroids[probes]           # (Q, P, D)
+    if rotation is not None:
+        residual = residual @ rotation
+    return residual.reshape(probes.shape[0] * probes.shape[1], -1)
+
+
+@jax.named_scope("DC")
 def gather_probed(clusters: PaddedClusters, flat_probes: jax.Array):
     """Codes (T, C, M), ids (T, C) and sizes (T,) of the probed clusters.
     A -1 probe (nprobe > nlist) reads cluster 0 but gets size 0 and ids
@@ -78,11 +114,7 @@ def _search_chunk(queries, centroids, codebook, clusters: PaddedClusters,
     q = queries.astype(jnp.float32)
     probes, _ = cluster_locate(q, centroids, params.nprobe)       # (Qc, P)
     qc, p = probes.shape
-    # RC
-    residual = q[:, None, :] - centroids[probes]                  # (Qc, P, D)
-    if rotation is not None:
-        residual = residual @ rotation
-    flat_res = residual.reshape(qc * p, -1)
+    flat_res = residuals(q, centroids, rotation, probes)          # (Qc*P, D)
     codes, ids, sizes = gather_probed(clusters, probes.reshape(-1))
     quantized = params.lut_dtype == "uint8"
     if params.use_kernels:

@@ -65,6 +65,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.ivf import IVFPQIndex, PaddedClusters
 from repro.core.pq import PQCodebook
+from repro.core.search import residuals
 from repro.core.adc import (QuantizedLUT, adc_distances,
                             adc_distances_quantized, build_lut_batch,
                             quantize_lut)
@@ -224,9 +225,7 @@ def _shard_tasks_fn(codes, ids, sizes, cluster_of, qidx, sidx, queries,
 
     q = queries[qi].astype(jnp.float32)                       # (T, D)
     cl = jnp.clip(cluster_of[si], 0, centroids.shape[0] - 1)
-    residual = q - centroids[cl]                              # (T, D) -- RC
-    if rotation is not None:
-        residual = residual @ rotation
+    residual = residuals(q, centroids, rotation, cl[:, None])  # (T, D)
     task_codes = codes[si]                                    # (T, cpart, M)
     task_ids = ids[si]                                        # (T, cpart)
     task_sizes = jnp.where(valid, sizes[si], 0)               # invalid -> 0
@@ -286,9 +285,7 @@ def _shard_tasks_scoped_fn(codes, ids, sizes, cluster_of, qidx, sidx,
 
     q = queries[qi].astype(jnp.float32)                       # (T, D)
     cl = jnp.clip(cluster_of[si], 0, centroids.shape[0] - 1)
-    residual = q - centroids[cl]                              # RC
-    if rotation is not None:
-        residual = residual @ rotation
+    residual = residuals(q, centroids, rotation, cl[:, None])  # (T, D)
     task_codes = codes[si]                                    # (T, cpart, M)
     task_ids = ids[si]                                        # (T, cpart)
     task_sizes = jnp.where(valid, sizes[si], 0)               # invalid -> 0
@@ -327,6 +324,7 @@ def run_shards_vmap_scoped(sindex: ShardedIndex, qidx: jax.Array,
     )(sindex.codes, sindex.ids, sindex.sizes, sindex.cluster_of, qidx, sidx)
 
 
+@jax.named_scope("DC")
 def _fused_scan_topk(lut, task_codes, task_ids, task_sizes, k: int,
                      block: int = 512):
     """Streaming DC+TS: scan over C-blocks, (T, k) running winners carried.
@@ -336,7 +334,8 @@ def _fused_scan_topk(lut, task_codes, task_ids, task_sizes, k: int,
     dry-run's lowered artifact reflects the reduced HBM writeback.
     ``lut`` may be a (T,)-batched :class:`QuantizedLUT`, in which case
     each block runs the u8 gather-and-scale scan (the fused mirror of
-    ``kernels/pq_scan.pq_scan_topk_q_pallas``).
+    ``kernels/pq_scan.pq_scan_topk_q_pallas``).  Its running top-k runs
+    inside the ``DC`` scope, so a trace counts it as DC.
     """
     from repro.core.adc import scan_codes, scan_codes_quantized
     scan_fn = (scan_codes_quantized if isinstance(lut, QuantizedLUT)
@@ -425,10 +424,8 @@ def miss_residuals(miss_queries: jax.Array, centroids: jax.Array,
     power of two, so the compiled shape depends only on the miss count
     (precompile_lc can warm every shape) and hit rows never pay the
     rotation matmul."""
-    residual = miss_queries.astype(jnp.float32) - centroids[crows]
-    if rotation is not None:
-        residual = residual @ rotation
-    return residual
+    return residuals(miss_queries.astype(jnp.float32), centroids, rotation,
+                     crows[:, None])
 
 
 def _shard_tasks_lut_fn(codes, ids, sizes, qidx, sidx, lidx, lut_bank, *,

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("TS")
 def topk_smallest(dists: jax.Array, ids: jax.Array, k: int):
     """k smallest along last axis. Returns (dists (..., k), ids (..., k))."""
     neg, idx = jax.lax.top_k(-dists, k)

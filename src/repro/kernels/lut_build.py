@@ -61,7 +61,7 @@ def _lut_build_kernel(res_ref, w_ref, s_ref, sqn_ref, out_ref):
 
 
 def _call(kernel, residuals, codebooks, sqnorms, out_specs, out_shape,
-          block_t, interpret, name):
+          block_t, interpret):
     t, d = residuals.shape
     m, cbn, _ = codebooks.shape
     assert t % block_t == 0, (t, block_t)
@@ -81,7 +81,7 @@ def _call(kernel, residuals, codebooks, sqnorms, out_specs, out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-        name=name,
+        name="lut_build",
     )(residuals.astype(jnp.float32), w, s,
       sqnorms.astype(jnp.float32).reshape(1, m * cbn))
 
@@ -97,7 +97,7 @@ def lut_build_pallas(residuals: jax.Array, codebooks: jax.Array,
     return _call(_lut_build_kernel, residuals, codebooks, sqnorms,
                  pl.BlockSpec((block_t, m * cbn), lambda i: (i, 0)),
                  jax.ShapeDtypeStruct((t, m * cbn), jnp.float32),
-                 block_t, interpret, "drim_lut_build")
+                 block_t, interpret)
 
 
 # --------------------------------------------------------------------------
@@ -150,4 +150,4 @@ def lut_build_q_pallas(residuals: jax.Array, codebooks: jax.Array,
         [jax.ShapeDtypeStruct((t, m * cbn), jnp.uint8),
          jax.ShapeDtypeStruct((t, m), jnp.float32),
          jax.ShapeDtypeStruct((t, m), jnp.float32)],
-        block_t, interpret, "drim_lut_build_q")
+        block_t, interpret)

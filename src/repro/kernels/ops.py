@@ -5,6 +5,11 @@ transposed so the candidate axis is the lane axis), padding to block
 multiples, and backend selection.  ``interpret`` defaults to True only on
 the CPU backend; everywhere else the kernels are compiled by Mosaic, and
 the ``onehot`` strategy is the one that compiles for the TPU.
+
+Each wrapper carries its phase's ``jax.named_scope`` (``LC`` for the LUT
+builds, ``DC`` for the scans; the fused scan's top-k counts as DC), and
+each ``pallas_call`` a stable ``name`` (``lut_build``, ``pq_scan``,
+``pq_scan_topk``) that a profiler trace shows for the kernel.
 """
 
 from __future__ import annotations
@@ -57,6 +62,7 @@ def _pad_rows(residuals: jax.Array, block_t: int):
     return residuals, bt
 
 
+@jax.named_scope("LC")
 def lut_build(residuals: jax.Array, codebooks: jax.Array,
               sqnorms: jax.Array, *, block_t: int = 64,
               interpret: bool | None = None) -> jax.Array:
@@ -70,6 +76,7 @@ def lut_build(residuals: jax.Array, codebooks: jax.Array,
     return out[:t].reshape(t, m, cbn)
 
 
+@jax.named_scope("LC")
 def lut_build_q(residuals: jax.Array, codebooks: jax.Array,
                 sqnorms: jax.Array, *, block_t: int = 64,
                 interpret: bool | None = None) -> QuantizedLUT:
@@ -104,6 +111,7 @@ def _scan_operands(lut, codes: jax.Array, block_c: int):
     return luts, codes_t, cbn
 
 
+@jax.named_scope("DC")
 def pq_scan_dc(lut, codes: jax.Array, sizes: jax.Array | None
                = None, *, strategy: str = "onehot",
                block_c: int | None = None,
@@ -125,6 +133,7 @@ def pq_scan_dc(lut, codes: jax.Array, sizes: jax.Array | None
     return d
 
 
+@jax.named_scope("DC")
 def pq_scan_topk(lut, codes: jax.Array, ids: jax.Array,
                  sizes: jax.Array, k: int, *, strategy: str = "onehot",
                  block_c: int | None = None, interpret: bool | None = None):

@@ -144,7 +144,7 @@ def pq_scan_dc_pallas(lut: jax.Array, scale, bias, codes: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-        name=f"drim_pq_scan_dc{'_q' if quantized else ''}_{strategy}",
+        name="pq_scan",
     )(*_lut_args(lut, scale, bias), codes.astype(jnp.int32))
 
 
@@ -257,7 +257,7 @@ def pq_scan_topk_pallas(lut: jax.Array, scale, bias, codes: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name=f"drim_pq_scan_topk{'_q' if quantized else ''}_{strategy}",
+        name="pq_scan_topk",
     )(sizes.astype(jnp.int32), *_lut_args(lut, scale, bias),
       codes.astype(jnp.int32), ids.astype(jnp.int32))
     return bd, bi

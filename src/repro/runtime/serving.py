@@ -58,7 +58,7 @@ from repro.core.filter import NO_TAG, VectorMeta, mask_scoped_distances
 from repro.core.ivf import IVFPQIndex, PaddedClusters
 from repro.core.search import (SearchParams, cluster_locate,
                                cluster_locate_masked, gather_probed,
-                               search_ivfpq)
+                               residuals, search_ivfpq)
 from repro.core.topk import topk_smallest
 from repro.runtime.batching import (BucketPolicy, MicroBatch, MicroBatcher,
                                     Request)
@@ -129,10 +129,7 @@ def _cl_rc(queries, centroids, rotation, *, nprobe: int):
     """CL + RC for the cached path: (Q, D) -> probes (Q, P), flat residuals
     (Q*P, D).  Jitted per bucket shape like the main pipeline."""
     probes, _ = cluster_locate(queries, centroids, nprobe)
-    residual = queries[:, None, :] - centroids[probes]
-    if rotation is not None:
-        residual = residual @ rotation
-    return probes, residual.reshape(probes.shape[0] * probes.shape[1], -1)
+    return probes, residuals(queries, centroids, rotation, probes)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "strategy", "nprobe"))
@@ -158,10 +155,7 @@ def _dc_ts(lut, flat_probes, clusters: PaddedClusters, *, k: int,
 def _rc_from_probes(queries, centroids, rotation, probes):
     """RC for externally-routed probes (two-level CL): (Q, D) + (Q, P)
     -> flat residuals (Q*P, D)."""
-    residual = queries[:, None, :] - centroids[probes]
-    if rotation is not None:
-        residual = residual @ rotation
-    return residual.reshape(probes.shape[0] * probes.shape[1], -1)
+    return residuals(queries, centroids, rotation, probes)
 
 
 @jax.jit
@@ -275,10 +269,7 @@ def _scoped_search_fused(queries, centroids, rotation, codebook,
     scope mask, TS, id epilogue.
     """
     probes, _ = cluster_locate_masked(queries, centroids, nprobe, allowed)
-    residual = queries[:, None, :] - centroids[probes]
-    if rotation is not None:
-        residual = residual @ rotation
-    flat_res = residual.reshape(queries.shape[0] * nprobe, -1)
+    flat_res = residuals(queries, centroids, rotation, probes)
     lut = build_lut_batch(codebook, flat_res)
     if lut_u8:
         lut = quantize_lut(lut)
@@ -296,6 +287,12 @@ def _scoped_search_fused(queries, centroids, rotation, codebook,
                                    q_tenants, q_terms)
     bd, bi = topk_smallest(cand_d, cand_i, k)
     return bd, jnp.where(jnp.isfinite(bd), bi, -1)
+
+
+def _fetch(x) -> np.ndarray:
+    """One device->host sync of a served path (an ``ann.fetch`` span)."""
+    with jax.profiler.TraceAnnotation("ann.fetch"):
+        return np.asarray(x)
 
 
 class LocalEngine:
@@ -402,8 +399,15 @@ class LocalEngine:
         index, clusters, _ = self._view
         self.last_batch_info = {"degraded": False, "dropped_probes": 0}
         scope = self._make_scope(tenants, terms)
+        staged = self.tiered_store is not None or self.lut_cache is not None
         if scope is not None:
-            if self.tiered_store is None and self.lut_cache is None:
+            path = "tasks" if staged else "scoped"
+        elif self.tiered_store is not None or self.coarse is not None:
+            path = "tasks"
+        else:
+            path = "cached" if self.lut_cache is not None else "fused"
+        with jax.profiler.TraceAnnotation("ann.engine", path=path):
+            if path == "scoped":
                 # all-resident, no cache: one fused dispatch (an
                 # all-true row of ``allowed`` reduces masked CL to
                 # plain CL exactly, so unscoped tenants in a mixed
@@ -417,21 +421,20 @@ class LocalEngine:
                     jnp.asarray(allowed), scope[0], scope[1], scope[2],
                     scope[3], k=p.k, strategy=p.strategy,
                     nprobe=p.nprobe, lut_u8=p.lut_dtype == "uint8")
-                return np.asarray(bd), np.asarray(bi)
-            # tiered / LUT-cached scoped traffic runs the task path:
-            # same LC/DC math, plus the tenant/predicate mask before TS
-            return self._search_tasks(np.asarray(queries, np.float32),
-                                      n_valid, budget_s, scope=scope)
-        if self.tiered_store is not None or self.coarse is not None:
-            return self._search_tasks(np.asarray(queries, np.float32),
-                                      n_valid, budget_s)
-        if self.lut_cache is None:
-            d, i = search_ivfpq(index, clusters,
-                                jnp.asarray(queries, jnp.float32),
-                                self.params)
-            return np.asarray(d), np.asarray(i)
-        return self._search_cached(np.asarray(queries, np.float32),
-                                   n_valid)
+                return _fetch(bd), _fetch(bi)
+            if path == "tasks":
+                # tiered and two-level routing, and scoped traffic on a
+                # tier or LUT cache: the staged task path (same LC/DC
+                # math; scoped rows masked before TS)
+                return self._search_tasks(np.asarray(queries, np.float32),
+                                          n_valid, budget_s, scope=scope)
+            if path == "fused":
+                d, i = search_ivfpq(index, clusters,
+                                    jnp.asarray(queries, jnp.float32),
+                                    self.params)
+                return _fetch(d), _fetch(i)
+            return self._search_cached(np.asarray(queries, np.float32),
+                                       n_valid)
 
     def _make_scope(self, tenants, terms):
         """Package per-query scope arrays (PR 10 tenant namespaces and
@@ -492,7 +495,7 @@ class LocalEngine:
         index, clusters, vgen = self._view    # one atomic read per batch
         probes, flat_res = _cl_rc(jnp.asarray(queries), index.centroids,
                                   index.rotation, nprobe=p.nprobe)
-        probes_np = np.asarray(probes)                     # (Q, P)
+        probes_np = _fetch(probes)                         # (Q, P)
         nq, npr = probes_np.shape
         flat_probes = probes_np.reshape(-1)
         n_valid_q = n_valid if n_valid is not None else nq
@@ -504,14 +507,14 @@ class LocalEngine:
         luts, miss_rows = lut_miss_scan(self.lut_cache, flat_probes,
                                         buckets, npr, nq * npr)
         if miss_rows:
-            flat_res_np = np.asarray(flat_res)
+            flat_res_np = _fetch(flat_res)
             lut_fill_misses(self.lut_cache, index.codebook, luts,
                             miss_rows, flat_probes, buckets, npr,
                             flat_res_np[miss_rows])
         lut = stack_lut_bank(luts)            # (QP, M, CB) or QuantizedLUT
         bd, bi = _dc_ts(lut, jnp.asarray(flat_probes), clusters,
                         k=p.k, strategy=p.strategy, nprobe=npr)
-        return np.asarray(bd), np.asarray(bi)
+        return _fetch(bd), _fetch(bi)
 
     def _route(self, queries_j, index):
         """CL + RC, flat or two-level: -> (probes (Q, P), flat residuals).
@@ -568,7 +571,7 @@ class LocalEngine:
                                        index.rotation, probes)
         else:
             probes, flat_res = self._route(queries_j, index)
-        probes_np = np.asarray(probes)                     # (Q, P)
+        probes_np = _fetch(probes)                         # (Q, P)
         nq, npr = probes_np.shape
         flat_probes = probes_np.reshape(-1)
         n_valid_q = n_valid if n_valid is not None else nq
@@ -581,7 +584,7 @@ class LocalEngine:
             luts, miss_rows = lut_miss_scan(self.lut_cache, flat_probes,
                                             buckets, npr, nq * npr)
             if miss_rows:
-                flat_res_np = np.asarray(flat_res)
+                flat_res_np = _fetch(flat_res)
                 lut_fill_misses(self.lut_cache, index.codebook, luts,
                                 miss_rows, flat_probes, buckets, npr,
                                 flat_res_np[miss_rows])
@@ -625,7 +628,7 @@ class LocalEngine:
         else:
             bd, bi = _dc_ts(lut, jnp.asarray(flat_probes), clusters,
                             k=p.k, strategy=p.strategy, nprobe=npr)
-        return np.asarray(bd), np.asarray(bi)
+        return _fetch(bd), _fetch(bi)
 
 
 class ShardedEngine:

@@ -35,6 +35,7 @@ import threading
 import time
 from typing import Callable, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.runtime.batching import MicroBatch, Request
@@ -117,7 +118,9 @@ class SearchFuture:
 
         Raises ``TimeoutError`` if ``timeout`` (seconds) elapses first,
         or the engine's exception if the request ultimately failed."""
-        if not self._event.wait(timeout):
+        with jax.profiler.TraceAnnotation("ann.result"):
+            served = self._event.wait(timeout)
+        if not served:
             raise TimeoutError(
                 f"request {self._request.req_id} not served within "
                 f"{timeout}s (queue depth may be growing faster than "
@@ -161,6 +164,7 @@ class ReplicaExecutor:
         self.on_batch_success = on_batch_success
         self.join_timeout_s = float(join_timeout_s)
         self.failures = 0
+        self.batches = 0              # micro-batches taken off the queue
         self.wedged = False
         self._cond = threading.Condition()
         self._stop = False
@@ -239,7 +243,7 @@ class ReplicaExecutor:
     def _wait_for_work(self) -> bool:
         """Sleep until there is something to flush.  Returns False when
         stopped with an empty queue (worker exits)."""
-        with self._cond:
+        with jax.profiler.TraceAnnotation("ann.wait"), self._cond:
             while True:
                 batcher = self.runtime.batcher
                 now = self.clock()
@@ -264,8 +268,13 @@ class ReplicaExecutor:
             batch = self.runtime.batcher.poll(self.clock(), drain=drain)
             if batch is None:
                 continue
+            self.batches += 1
             try:
-                self.runtime.serve_flushed(batch, t_start=self.clock())
+                with jax.profiler.TraceAnnotation(
+                        "ann.batch", replica=self.replica_idx,
+                        batch_id=self.batches, bucket=batch.bucket,
+                        n_valid=batch.n_valid, reason=batch.reason):
+                    self.runtime.serve_flushed(batch, t_start=self.clock())
                 if self.on_batch_success is not None:
                     self.on_batch_success(self.replica_idx)
             except BatchServeError as err:

@@ -61,6 +61,7 @@ import threading
 import time
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -281,10 +282,11 @@ class AnnService:
         def probe_fn(q: np.ndarray) -> np.ndarray:
             # read centroids through the handle so routing follows the
             # live generation (maintenance may split/merge clusters)
-            p, _ = cluster_locate(
-                jnp.asarray(np.asarray(q, np.float32)[None]),
-                handle.centroids, spec.nprobe)
-            p = np.asarray(p)[0]
+            with jax.profiler.TraceAnnotation("ann.route"):
+                p, _ = cluster_locate(
+                    jnp.asarray(np.asarray(q, np.float32)[None]),
+                    handle.centroids, spec.nprobe)
+                p = np.asarray(p)[0]
             return p[p >= 0]            # -1: nprobe > nlist after merges
 
         svc = cls.__new__(cls)
@@ -601,17 +603,18 @@ class AnnService:
         per-vector metadata.  Quotas do not apply on this offline path
         (admission control guards the *online* submit paths)."""
         self._check_open()
-        r = self._batch_rr % self.n_replicas
-        self._batch_rr += 1
-        q = np.asarray(queries, np.float32)
-        tid = self._resolve_tenant(tenant)
-        if tid < 0 and not len(tuple(terms)):
-            return self.replicas[r].engine.search_batch(q)
-        tenants_arr = np.full(len(q), tid, np.int32)
-        terms_arr = pad_terms([tuple(terms)] * len(q),
-                              self.spec.filter_width)
-        return self.replicas[r].engine.search_batch(
-            q, tenants=tenants_arr, terms=terms_arr)
+        with jax.profiler.TraceAnnotation("ann.search"):
+            r = self._batch_rr % self.n_replicas
+            self._batch_rr += 1
+            q = np.asarray(queries, np.float32)
+            tid = self._resolve_tenant(tenant)
+            if tid < 0 and not len(tuple(terms)):
+                return self.replicas[r].engine.search_batch(q)
+            tenants_arr = np.full(len(q), tid, np.int32)
+            terms_arr = pad_terms([tuple(terms)] * len(q),
+                                  self.spec.filter_width)
+            return self.replicas[r].engine.search_batch(
+                q, tenants=tenants_arr, terms=terms_arr)
 
     # -- async request lifecycle --------------------------------------------
     def _route_and_submit(self, query, now: float, executor: bool,
@@ -750,10 +753,11 @@ class AnnService:
         self._check_open()
         self._check_wall_ok("submit_async()")
         self._ensure_executors()
-        t = float(now) if now is not None else time.monotonic()
-        return self._route_and_submit(query, t, executor=True,
-                                      tenant=self._resolve_tenant(tenant),
-                                      terms=tuple(terms))
+        with jax.profiler.TraceAnnotation("ann.submit"):
+            t = float(now) if now is not None else time.monotonic()
+            return self._route_and_submit(
+                query, t, executor=True,
+                tenant=self._resolve_tenant(tenant), terms=tuple(terms))
 
     # -- old sync surface: thin wrappers over the same lifecycle -----------
     def submit(self, query, now: float, *, tenant=None,

@@ -10,6 +10,8 @@ The topology is described inside a module fixture — never at import —
 because only one process may load the TPU library at a time.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -118,3 +120,60 @@ def test_gather_kernel_refused_off_interpret():
     codes = jnp.zeros((2, 8, M), jnp.uint8)
     with pytest.raises(ValueError, match="interpret"):
         ops.pq_scan_dc(lut, codes, strategy="gather", interpret=False)
+
+
+PHASES = ("CL", "RC", "LC", "DC", "TS")
+COSTLY = ("fusion", "custom-call", "sort", "gather", "dot")
+
+
+def _computation(hlo: str, name: str) -> list:
+    """The instruction lines of one computation of an HLO module's text."""
+    lines, inside = [], False
+    for line in hlo.splitlines():
+        if not line.startswith(" "):
+            inside = re.match(rf"(ENTRY )?%{re.escape(name)} ", line)
+            continue
+        if inside:
+            lines.append(line)
+    return lines
+
+
+@pytest.mark.parametrize("lut_dtype", ["f32", "uint8"])
+def test_served_step_names_one_phase_per_costly_op(one_chip, lut_dtype):
+    """Every costly instruction of the compiled query-chunk loop body names
+    the phase it belongs to: its ``op_name`` path holds CL, RC, LC, DC or
+    TS (the outermost of them owns it).  Left out are instructions the
+    compiler made with no source op (no ``op_name`` at all, such as its
+    ``ConcatBitcast`` custom-calls) and the loop's own slicing of the
+    query chunk and stacking of its results."""
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    idx = IVFPQIndex(s((NLIST, D), jnp.float32),
+                     PQCodebook(s((M, CB, DSUB), jnp.float32),
+                                s((M, CB), jnp.float32)),
+                     s((N, M), jnp.uint8), s((N,), jnp.int32),
+                     s((NLIST + 1,), jnp.int32))
+    cl = PaddedClusters(s((NLIST, CMAX, M), jnp.uint8),
+                        s((NLIST, CMAX), jnp.int32), s((NLIST,), jnp.int32))
+    p = SearchParams(nprobe=NPROBE, k=K, lut_dtype=lut_dtype,
+                     query_chunk=Q // 2)               # two loop steps
+    hlo = jax.jit(lambda i, c, q: search_ivfpq(i, c, q, p)).lower(
+        idx, cl, s((Q, D), jnp.float32)).compile().as_text()
+    (body,) = set(re.findall(r"while\(.*?body=%([\w.\-]+)", hlo))
+    named, seen = [], set()
+    for line in _computation(hlo, body):
+        head = line.split(", metadata=")[0]
+        op = re.search(r" (" + "|".join(COSTLY) + r")\(", head)
+        path = re.search(r'op_name="([^"]*)"', line)
+        if op is None or path is None:
+            continue
+        path = path.group(1)
+        if re.search(r"/while/body/dynamic_(update_)?slice$", path):
+            continue                       # the loop's own chunking
+        phase = next((x for x in path.split("/") if x in PHASES), None)
+        named.append((head.split(" = ")[0].strip(), path, phase))
+        seen.add(phase)
+    assert named
+    assert [n for n in named if n[2] is None] == []
+    assert seen == set(PHASES)
